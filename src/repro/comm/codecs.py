@@ -265,8 +265,12 @@ class Channel:
 
     # -------------------------------------------------- encode / decode
 
+    @jax.named_scope("comm/encode")
     def encode(self, x: jnp.ndarray, key: jax.Array, *,
                rounding: str = "stochastic") -> dict:
+        return self._encode(x, key, rounding=rounding)
+
+    def _encode(self, x, key, *, rounding="stochastic") -> dict:
         c = self.cfg
         if c.codec in ("int8", "int4"):
             return quant_encode(x, key, bits=_quant_bits(c.codec),
@@ -349,6 +353,7 @@ class Channel:
             return None
         return jnp.zeros(tuple(batch_prefix) + (self.x,), jnp.float32)
 
+    @jax.named_scope("comm/encode")
     def encode_stream(self, x: jnp.ndarray, key: jax.Array,
                       ef: Optional[jnp.ndarray], *, need_hat: bool = False):
         """One channel use: returns (enc, x_hat_or_None, ef').
@@ -357,7 +362,7 @@ class Channel:
         residual update or the caller (``need_hat``) demands it — the
         fused Pallas path without EF never decodes outside the kernel."""
         msg = x.astype(jnp.float32) + ef if ef is not None else x
-        enc = self.encode(msg, key)
+        enc = self._encode(msg, key)
         x_hat = None
         if self.has_ef or need_hat:
             x_hat = self.decode(enc)

@@ -438,7 +438,9 @@ def make_round_step(
         else:
             c_mixed, ef = _channel_mix(c_new, s, k_comm, ef, adj)
         n = s.shape[0]
-        plane = plane.at[s, jnp.arange(n)].set(c_mixed.astype(plane.dtype))
+        with jax.named_scope("fedspd/scatter"):
+            plane = plane.at[s, jnp.arange(n)].set(
+                c_mixed.astype(plane.dtype))
         return plane, ef
 
     # ---------------- sparse (DisPFL) plane machinery ---------------------
@@ -495,8 +497,9 @@ def make_round_step(
             jnp.logical_and(smask > 0, den > 0),
             num / jnp.maximum(den, 1e-12), c_sel,
         )
-        plane = plane.at[s, jnp.arange(s.shape[0])].set(
-            c_mixed.astype(plane.dtype))
+        with jax.named_scope("fedspd/scatter"):
+            plane = plane.at[s, jnp.arange(s.shape[0])].set(
+                c_mixed.astype(plane.dtype))
         return plane, ef
 
     def dense_grads(c_flat, data, z, s, key):
@@ -537,6 +540,19 @@ def make_round_step(
         return maybe_update_mask(
             state.mask, c_new, g_dense, k_grow, state.round, sparse
         )
+
+    def assign_batch(centers_i, batch_i):
+        """Stream regime: each example of client i's batch to the cluster
+        whose center (of client i's S) has the least loss. (B,)"""
+        losses = jax.vmap(lambda c: per_example_loss(c, batch_i))(centers_i)
+        return jnp.argmin(losses, axis=0)
+
+    def ema_u(u_old, zb):
+        """Stream regime: u as an EMA of the batch assignment fractions."""
+        u_batch = jax.vmap(
+            lambda z_: mixture_coefficients(z_, cfg.n_clusters)
+        )(zb)
+        return (1 - cfg.u_ema) * u_old + cfg.u_ema * u_batch
 
     def local_updates(c_sel, data, z, s, key, lr, grad_mask=None):
         """τ SGD steps on the selected centers, cluster-conditional batches.
@@ -583,22 +599,26 @@ def make_round_step(
         lr = lr_schedule(state.round)
 
         # (1) cluster selection + τ local steps
-        s = select_clusters(k_sel, state.u)
-        c_sel = _gather_selected(state.centers, s)
-        c_new = local_updates(c_sel, data, state.z, s, k_local, lr)
+        with jax.named_scope("fedspd/select"):
+            s = select_clusters(k_sel, state.u)
+            c_sel = _gather_selected(state.centers, s)
+        with jax.named_scope("fedspd/local_step"):
+            c_new = local_updates(c_sel, data, state.z, s, k_local, lr)
         key, k_dp = jax.random.split(key)
         c_sel = dp_sanitize(c_sel, c_new, k_dp)
 
         # (2)+(3) exchange & cluster-matched averaging
         c_mixed = _plain_mix(c_sel, s, adj)
-        centers = _scatter_selected(state.centers, s, c_mixed)
+        with jax.named_scope("fedspd/scatter"):
+            centers = _scatter_selected(state.centers, s, c_mixed)
 
         # (4) re-cluster all local data and refresh u
         batch_all = {"x": data["inputs"], "y": data["targets"]}
-        z, u = cluster_all_clients(
-            per_example_loss, centers, batch_all, cfg.n_clusters,
-            chunk=cfg.cluster_chunk,
-        )
+        with jax.named_scope("fedspd/cluster"):
+            z, u = cluster_all_clients(
+                per_example_loss, centers, batch_all, cfg.n_clusters,
+                chunk=cfg.cluster_chunk,
+            )
 
         comm = state.comm_bytes + round_comm_bytes(
             gossip, s, model_b_of(c_sel), point_to_point=cfg.point_to_point,
@@ -608,10 +628,12 @@ def make_round_step(
             centers=centers, u=u, z=z, round=state.round + 1, key=key,
             comm_bytes=comm, mask=state.mask,
         )
+        with jax.named_scope("fedspd/consensus"):
+            consensus = _consensus_per_cluster(centers, cfg.n_clusters)
         metrics = {
             "lr": lr,
             "selected": s,
-            "consensus": _consensus_per_cluster(centers, cfg.n_clusters),
+            "consensus": consensus,
             "comm_bytes": comm,
         }
         return new_state, metrics
@@ -620,31 +642,29 @@ def make_round_step(
         """batch leaves (N, B, ...): this round's fresh per-client data."""
         key, k_sel, k_local = jax.random.split(state.key, 3)
         lr = lr_schedule(state.round)
-        s = select_clusters(k_sel, state.u)
-        c_sel = _gather_selected(state.centers, s)
+        with jax.named_scope("fedspd/select"):
+            s = select_clusters(k_sel, state.u)
+            c_sel = _gather_selected(state.centers, s)
 
         # per-batch clustering under *current* centers (Step 4, streamed)
-        centers_nc = jax.tree.map(lambda l: jnp.swapaxes(l, 0, 1), state.centers)
+        with jax.named_scope("fedspd/cluster"):
+            centers_nc = jax.tree.map(lambda l: jnp.swapaxes(l, 0, 1),
+                                      state.centers)
+            zb = jax.vmap(assign_batch)(centers_nc, batch)  # (N, B)
+            mask = (zb == s[:, None]).astype(jnp.float32)
 
-        def assign(centers_i, batch_i):
-            losses = jax.vmap(lambda c: per_example_loss(c, batch_i))(centers_i)
-            return jnp.argmin(losses, axis=0)  # (B,)
-
-        zb = jax.vmap(assign)(centers_nc, batch)  # (N, B)
-        mask = (zb == s[:, None]).astype(jnp.float32)
-
-        c_new = local_updates(
-            c_sel, {"batch": batch, "mask": mask}, None, s, k_local, lr
-        )
+        with jax.named_scope("fedspd/local_step"):
+            c_new = local_updates(
+                c_sel, {"batch": batch, "mask": mask}, None, s, k_local, lr
+            )
         key, k_dp = jax.random.split(key)
         c_sel = dp_sanitize(c_sel, c_new, k_dp)
         c_mixed = _plain_mix(c_sel, s, adj)
-        centers = _scatter_selected(state.centers, s, c_mixed)
+        with jax.named_scope("fedspd/scatter"):
+            centers = _scatter_selected(state.centers, s, c_mixed)
 
-        u_batch = jax.vmap(
-            lambda z_: mixture_coefficients(z_, cfg.n_clusters)
-        )(zb)
-        u = (1 - cfg.u_ema) * state.u + cfg.u_ema * u_batch
+        with jax.named_scope("fedspd/cluster"):
+            u = ema_u(state.u, zb)
 
         comm = state.comm_bytes + round_comm_bytes(
             gossip, s, model_b_of(c_sel), point_to_point=cfg.point_to_point,
@@ -654,10 +674,12 @@ def make_round_step(
             centers=centers, u=u, z=state.z, round=state.round + 1, key=key,
             comm_bytes=comm, mask=state.mask,
         )
+        with jax.named_scope("fedspd/consensus"):
+            consensus = _consensus_per_cluster(centers, cfg.n_clusters)
         metrics = {
             "lr": lr,
             "selected": s,
-            "consensus": _consensus_per_cluster(centers, cfg.n_clusters),
+            "consensus": consensus,
             "comm_bytes": comm,
         }
         return new_state, metrics
@@ -672,20 +694,22 @@ def make_round_step(
         # (1) cluster selection + τ local steps. gather = ONE dynamic
         # slice on the plane; the local-SGD scan needs model structure, so
         # parameters take pytree form only inside this scope.
-        s = select_clusters(k_sel, state.u)
-        c_old = _select_rows(plane, s)              # (N, X)
-        if sparse_on:
-            # support applies at gather: rows of OTHER clusters may carry
-            # coordinates from an older mask; the current mask projects
-            c_old = state.mask * c_old
-            grad_mask = unpack(state.mask, pack_spec)
-        else:
-            grad_mask = None
-        c_new_tree = local_updates(
-            unpack(c_old, pack_spec), data, state.z, s, k_local, lr,
-            grad_mask=grad_mask,
-        )
-        c_new = pack(c_new_tree, pack_spec)
+        with jax.named_scope("fedspd/select"):
+            s = select_clusters(k_sel, state.u)
+            c_old = _select_rows(plane, s)          # (N, X)
+            if sparse_on:
+                # support applies at gather: rows of OTHER clusters may
+                # carry coordinates from an older mask; the current mask
+                # projects
+                c_old = state.mask * c_old
+        with jax.named_scope("fedspd/local_step"):
+            grad_mask = (unpack(state.mask, pack_spec) if sparse_on
+                         else None)
+            c_new_tree = local_updates(
+                unpack(c_old, pack_spec), data, state.z, s, k_local, lr,
+                grad_mask=grad_mask,
+            )
+            c_new = pack(c_new_tree, pack_spec)
         if channel is None:
             key, k_dp = jax.random.split(key)
             k_comm = None
@@ -704,10 +728,11 @@ def make_round_step(
 
         # (4) re-cluster: the forward pass needs model structure again
         batch_all = {"x": data["inputs"], "y": data["targets"]}
-        z, u = cluster_all_clients(
-            per_example_loss, unpack(plane, pack_spec), batch_all,
-            cfg.n_clusters, chunk=cfg.cluster_chunk,
-        )
+        with jax.named_scope("fedspd/cluster"):
+            z, u = cluster_all_clients(
+                per_example_loss, unpack(plane, pack_spec), batch_all,
+                cfg.n_clusters, chunk=cfg.cluster_chunk,
+            )
 
         comm = state.comm_bytes + round_comm_bytes(
             gossip, s, model_b_of(None), point_to_point=cfg.point_to_point,
@@ -717,10 +742,12 @@ def make_round_step(
             centers=plane, u=u, z=z, round=state.round + 1, key=key,
             comm_bytes=comm, ef=ef, mask=new_mask,
         )
+        with jax.named_scope("fedspd/consensus"):
+            consensus = _consensus_per_cluster_flat(plane)
         metrics = {
             "lr": lr,
             "selected": s,
-            "consensus": _consensus_per_cluster_flat(plane),
+            "consensus": consensus,
             "comm_bytes": comm,
         }
         return new_state, metrics
@@ -729,31 +756,28 @@ def make_round_step(
         plane = state.centers                       # (S, N, X)
         key, k_sel, k_local = jax.random.split(state.key, 3)
         lr = lr_schedule(state.round)
-        s = select_clusters(k_sel, state.u)
-        c_old = _select_rows(plane, s)              # (N, X)
+        with jax.named_scope("fedspd/select"):
+            s = select_clusters(k_sel, state.u)
+            c_old = _select_rows(plane, s)          # (N, X)
+            if sparse_on:
+                c_old = state.mask * c_old
 
         # per-batch clustering under *current* centers (model structure)
-        centers_nc = jax.tree.map(
-            lambda l: jnp.swapaxes(l, 0, 1), unpack(plane, pack_spec)
-        )
+        with jax.named_scope("fedspd/cluster"):
+            centers_nc = jax.tree.map(
+                lambda l: jnp.swapaxes(l, 0, 1), unpack(plane, pack_spec)
+            )
+            zb = jax.vmap(assign_batch)(centers_nc, batch)  # (N, B)
+            mask = (zb == s[:, None]).astype(jnp.float32)
 
-        def assign(centers_i, batch_i):
-            losses = jax.vmap(lambda c: per_example_loss(c, batch_i))(centers_i)
-            return jnp.argmin(losses, axis=0)  # (B,)
-
-        zb = jax.vmap(assign)(centers_nc, batch)  # (N, B)
-        mask = (zb == s[:, None]).astype(jnp.float32)
-
-        if sparse_on:
-            c_old = state.mask * c_old
-            grad_mask = unpack(state.mask, pack_spec)
-        else:
-            grad_mask = None
-        c_new_tree = local_updates(
-            unpack(c_old, pack_spec), {"batch": batch, "mask": mask},
-            None, s, k_local, lr, grad_mask=grad_mask,
-        )
-        c_new = pack(c_new_tree, pack_spec)
+        with jax.named_scope("fedspd/local_step"):
+            grad_mask = (unpack(state.mask, pack_spec) if sparse_on
+                         else None)
+            c_new_tree = local_updates(
+                unpack(c_old, pack_spec), {"batch": batch, "mask": mask},
+                None, s, k_local, lr, grad_mask=grad_mask,
+            )
+            c_new = pack(c_new_tree, pack_spec)
         if channel is None:
             key, k_dp = jax.random.split(key)
             k_comm = None
@@ -770,10 +794,8 @@ def make_round_step(
             plane, ef = exchange_packed(plane, c_old, c_new, s, k_dp, k_comm,
                                         state.ef, adj)
 
-        u_batch = jax.vmap(
-            lambda z_: mixture_coefficients(z_, cfg.n_clusters)
-        )(zb)
-        u = (1 - cfg.u_ema) * state.u + cfg.u_ema * u_batch
+        with jax.named_scope("fedspd/cluster"):
+            u = ema_u(state.u, zb)
 
         comm = state.comm_bytes + round_comm_bytes(
             gossip, s, model_b_of(None), point_to_point=cfg.point_to_point,
@@ -783,10 +805,12 @@ def make_round_step(
             centers=plane, u=u, z=state.z, round=state.round + 1, key=key,
             comm_bytes=comm, ef=ef, mask=new_mask,
         )
+        with jax.named_scope("fedspd/consensus"):
+            consensus = _consensus_per_cluster_flat(plane)
         metrics = {
             "lr": lr,
             "selected": s,
-            "consensus": _consensus_per_cluster_flat(plane),
+            "consensus": consensus,
             "comm_bytes": comm,
         }
         return new_state, metrics

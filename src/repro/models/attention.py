@@ -33,6 +33,7 @@ def _split_gqa(q: jnp.ndarray, n_kv: int) -> jnp.ndarray:
     return q.reshape(b, l, n_kv, hq // n_kv, hd)
 
 
+@jax.named_scope("attn")
 def ref_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
@@ -89,6 +90,7 @@ def _fit_block(length: int, block: int) -> int:
     return block
 
 
+@jax.named_scope("attn")
 def blocked_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,

@@ -18,9 +18,17 @@ and consumed by a vmapped forward/decode immediately. Each entry point is
 ONE jitted program: ``n_compiles`` (via the jit cache size, same
 accounting as the train engines) and ``n_dispatches`` are exposed so
 tests can assert one-compile/one-dispatch-per-call.
+
+On a profiler trace (``--profile-dir``) each entry-point call is one host
+span ``serve/call`` (stats ``call`` = its ``n_dispatches``, ``batch``),
+holding ``serve/dispatch`` (the jitted call returning) and
+``serve/block`` (the wait for the device); inside the program the mix
+runs under ``serve/mix_<codec>`` and the unpack into the model's leaves
+under ``serve/unpack``.
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Any
 
@@ -35,7 +43,7 @@ from repro.kernels.gossip_mix import (
     mixture_mix_dequant4,
 )
 from repro.serve.artifact import ServableArtifact
-from repro.telemetry import LatencyStats, compile_count
+from repro.telemetry import LatencyStats, annotate, compile_count
 
 
 class ClusterPlaneServer:
@@ -97,15 +105,24 @@ class ClusterPlaneServer:
             static_argnames=("gen", "temperature", "max_len"),
         )
 
-    def _timed(self, fn, batch: int):
-        """Dispatch one entry-point batch and record its request latency
-        (dispatch + device completion — what a caller actually waits)."""
+    @contextlib.contextmanager
+    def _call(self, batch: int):
+        """One entry-point call: counts it and spans it (``serve/call``)
+        from argument conversion to return."""
         self.n_dispatches += 1
         if self.codec != "fp32":
             self.dequant_calls += 1
+        with annotate("serve/call", call=self.n_dispatches, batch=batch):
+            yield
+
+    def _timed(self, fn, batch: int):
+        """Dispatch one entry-point batch and record its request latency
+        (dispatch + device completion — what a caller actually waits)."""
         t0 = time.perf_counter()
-        out = fn()
-        jax.block_until_ready(out)
+        with annotate("serve/dispatch"):
+            out = fn()
+        with annotate("serve/block"):
+            jax.block_until_ready(out)
         self.latency.record(time.perf_counter() - t0, batch=batch)
         return out
 
@@ -148,16 +165,23 @@ class ClusterPlaneServer:
 
     # -- entry points (each ONE jitted program) --------------------------
 
+    def _unpack(self, flat: jnp.ndarray) -> Any:
+        """(B, X) -> the model's leaves, (B,)-leading: the re-layout of the
+        mixed rows, scoped apart from the mix."""
+        with jax.named_scope("serve/unpack"):
+            return unpack(flat, self.spec)
+
     def _personalized_impl(self, u):
-        return unpack(self._mix(u), self.spec)
+        return self._unpack(self._mix(u))
 
     def personalized(self, u) -> Any:
         """(B, S) -> personalized params pytree with (B,)-leading leaves."""
-        u = jnp.asarray(u)
-        return self._timed(lambda: self._personalized(u), u.shape[0])
+        with self._call(np.shape(u)[0]):
+            u = jnp.asarray(u)
+            return self._timed(lambda: self._personalized(u), u.shape[0])
 
     def _predict_impl(self, u, inputs):
-        params = unpack(self._mix(u), self.spec)
+        params = self._unpack(self._mix(u))
 
         def one(p, x):
             return self.apply_fn(p, x[None, ...])[0]
@@ -169,14 +193,15 @@ class ClusterPlaneServer:
         mixture — mix, unpack, and the vmapped apply in one program."""
         if self.apply_fn is None:
             raise ValueError("predict needs apply_fn= at construction")
-        u = jnp.asarray(u)
-        inputs = jnp.asarray(inputs)
-        return self._timed(lambda: self._predict(u, inputs), u.shape[0])
+        with self._call(np.shape(u)[0]):
+            u = jnp.asarray(u)
+            inputs = jnp.asarray(inputs)
+            return self._timed(lambda: self._predict(u, inputs), u.shape[0])
 
     def _generate_impl(self, u, prompts, key, *, gen, temperature, max_len):
         bundle = self.bundle
         vocab = bundle.cfg.vocab
-        params = unpack(self._mix(u), self.spec)
+        params = self._unpack(self._mix(u))
         lp = prompts.shape[1]
 
         # per-request prefill: pos lands at lp statically, so the first
@@ -221,18 +246,19 @@ class ClusterPlaneServer:
         lax.scan over the gen tokens). Returns (B, gen) int32 tokens."""
         if self.bundle is None:
             raise ValueError("generate needs bundle= at construction")
-        prompts = jnp.asarray(prompts, jnp.int32)
-        if key is None:
-            key = jax.random.PRNGKey(0)
-        max_len = prompts.shape[1] + int(gen) + 1
-        u = jnp.asarray(u)
-        return self._timed(
-            lambda: self._generate(
-                u, prompts, key, gen=int(gen),
-                temperature=float(temperature), max_len=max_len,
-            ),
-            prompts.shape[0],
-        )
+        with self._call(np.shape(prompts)[0]):
+            prompts = jnp.asarray(prompts, jnp.int32)
+            if key is None:
+                key = jax.random.PRNGKey(0)
+            max_len = prompts.shape[1] + int(gen) + 1
+            u = jnp.asarray(u)
+            return self._timed(
+                lambda: self._generate(
+                    u, prompts, key, gen=int(gen),
+                    temperature=float(temperature), max_len=max_len,
+                ),
+                prompts.shape[0],
+            )
 
     def serve_client(self, client: int, prompts, *, gen: int,
                      temperature: float = 0.0, key=None) -> jnp.ndarray:

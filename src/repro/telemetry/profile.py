@@ -3,11 +3,24 @@
 ``trace_session(dir)`` wraps a whole run in ``jax.profiler.start_trace``
 / ``stop_trace`` (a no-op context when ``dir`` is falsy — the
 ``--profile-dir`` gate in both launchers).  ``annotate``/``step_annotation``
-mark HOST-side regions (round dispatches, serve batches) on the trace
-timeline; traced-code regions (the gossip mix, the serve-side plane
-contraction) are labelled with ``jax.named_scope`` at their definition
-sites instead, since host annotations cannot see inside a compiled
-program.
+mark HOST-side regions (round dispatches, serve calls) on the trace
+timeline; regions inside a compiled program are labelled with
+``jax.named_scope`` at their definition sites instead, since host
+annotations cannot see inside it. The names on a trace:
+
+    host spans                      where
+    repro/round (step number)       experiment loop engine, per round
+    serve/call (call, batch)        ClusterPlaneServer entry points
+      serve/dispatch, serve/block   the jitted call; the device wait
+    named scopes (a device op's tf_op path)
+    fedspd/select                   round step: cluster draw, row select
+    fedspd/cluster                  batch assignment and u, re-clustering
+    fedspd/local_step               unpack, tau SGD steps, pack
+    fedspd/scatter                  mixed rows written into the plane
+    fedspd/consensus                the per-cluster consensus metric
+    comm/encode                     the codec's encode
+    attn                            reference and blocked attention
+    gossip/mix_dense, gossip/mix_permute, serve/mix_<codec>, serve/unpack
 
 Open the result at https://ui.perfetto.dev (or
 ``tensorboard --logdir <dir>``): the ``.trace.json.gz`` under
@@ -36,12 +49,13 @@ def trace_session(profile_dir=None):
         jax.profiler.stop_trace()
 
 
-def annotate(name: str):
-    """Named host-span context (TraceAnnotation); no-op off-trace."""
+def annotate(name: str, **stats):
+    """Named host-span context (TraceAnnotation); no-op off-trace.
+    ``stats`` (numbers or strings) ride the span as its trace stats."""
     try:
         import jax
 
-        return jax.profiler.TraceAnnotation(name)
+        return jax.profiler.TraceAnnotation(name, **stats)
     except Exception:
         return contextlib.nullcontext()
 

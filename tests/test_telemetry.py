@@ -216,6 +216,29 @@ def test_streams_parity_fully_composed(setup):
                                   scan.extras["staleness"])
 
 
+def test_logical_bytes_under_cohort_dropout_and_heterogeneity(setup):
+    """The round's logical-bytes counter under the fully composed
+    scenario, with clients that are mostly available: it charges the
+    links that exchanged, and the int8 wire bytes stay below it. (With the
+    harsher system model above, by the fourth round every client has timed
+    out or is unavailable, and no active pair of neighbours selected the
+    same cluster in any round, so that run moves no bytes at all.)"""
+    exp, data = setup
+    het = ClientSystemModel(p_unavailable=0.1, staleness_gamma=0.7, seed=11)
+    cfg = RunConfig(
+        param_plane=True, eval_every=2, cohort_size=4,
+        scenario=Scenario(dropout=0.2, seed=11, system=het),
+        comm=CommConfig(codec="int8", error_feedback=True),
+        telemetry=TelemetryConfig(),
+    )
+    s = run_method("fedspd", data, exp, seed=0, cfg=cfg).telemetry["streams"]
+    moved = s["logical_bytes"] > 0
+    assert moved.any()
+    assert np.all(s["wire_bytes"][moved] < s["logical_bytes"][moved])
+    assert np.all(s["logical_bytes"][~moved] == 0)
+    assert np.all(s["wire_bytes"][~moved] == 0)
+
+
 def test_telemetry_on_does_not_change_training(setup):
     exp, data = setup
     for scan_rounds in (False, True):
