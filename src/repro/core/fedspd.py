@@ -215,6 +215,21 @@ def _select_rows(plane: jnp.ndarray, s: jnp.ndarray) -> jnp.ndarray:
     return rows
 
 
+def _write_rows(plane: jnp.ndarray, s: jnp.ndarray,
+                rows: jnp.ndarray) -> jnp.ndarray:
+    """The inverse of ``_select_rows``: client i's row goes into cluster
+    s_i of the (S, N, X) plane, the other rows stay. One broadcast select
+    whose output sits at the input's index, so XLA updates a donated plane
+    in place; the equivalent ``plane.at[s, arange(N)].set(rows)`` lowers
+    on TPU to a loop of dynamic-update-slices at about 5% of a v5e's HBM
+    bandwidth. The barrier keeps XLA from fusing the select into the
+    plane's readers (the consensus reductions), which would then each
+    read the old plane and the rows in place of the written plane."""
+    hit = jnp.arange(plane.shape[0])[:, None] == s[None, :]      # (S, N)
+    return jax.lax.optimization_barrier(
+        jnp.where(hit[:, :, None], rows[None].astype(plane.dtype), plane))
+
+
 def _scatter_selected(centers: PyTree, s: jnp.ndarray, value: PyTree) -> PyTree:
     n = s.shape[0]
     return jax.tree.map(
@@ -415,7 +430,7 @@ def make_round_step(
 
     def exchange_packed(plane, c_old, c_new, s, k_dp, k_comm, ef, adj):
         """Steps (2)+(3) on the flat plane: DP sanitize, wire codec,
-        Eq. (1) mix, and the scatter back into (S, N, X) — all
+        Eq. (1) mix, and the write-back into (S, N, X) — all
         single-array ops. When the mix backend exposes a fused
         clip·scale+W·C kernel (Pallas), no cosine filtering is on (the
         weight matrix must not depend on the sanitized values), and no
@@ -437,10 +452,8 @@ def make_round_step(
                 c_mixed, ef = _channel_mix(c_sel, s, k_comm, ef, adj)
         else:
             c_mixed, ef = _channel_mix(c_new, s, k_comm, ef, adj)
-        n = s.shape[0]
         with jax.named_scope("fedspd/scatter"):
-            plane = plane.at[s, jnp.arange(n)].set(
-                c_mixed.astype(plane.dtype))
+            plane = _write_rows(plane, s, c_mixed)
         return plane, ef
 
     # ---------------- sparse (DisPFL) plane machinery ---------------------
@@ -498,8 +511,7 @@ def make_round_step(
             num / jnp.maximum(den, 1e-12), c_sel,
         )
         with jax.named_scope("fedspd/scatter"):
-            plane = plane.at[s, jnp.arange(s.shape[0])].set(
-                c_mixed.astype(plane.dtype))
+            plane = _write_rows(plane, s, c_mixed)
         return plane, ef
 
     def dense_grads(c_flat, data, z, s, key):

@@ -11,6 +11,8 @@ from repro.core.clustering import (
 )
 from repro.core.fedspd import (
     FedSPDConfig,
+    _select_rows,
+    _write_rows,
     seeded_init,
     final_phase,
     init_state,
@@ -252,3 +254,23 @@ def test_fedspd_learns_mixture_end_to_end():
     direct = np.abs(u - mt).mean()
     flipped = np.abs(u - mt[:, ::-1]).mean()
     assert min(direct, flipped) < 0.2
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("n", [1, 2, 5])
+@pytest.mark.parametrize("s_count", [1, 2, 3])
+def test_write_rows_matches_scatter(s_count, n, dtype):
+    """The plane write-back is bitwise the row scatter it replaces, and
+    the row select reads back what it wrote."""
+    k_plane, k_rows, k_s = jax.random.split(
+        jax.random.PRNGKey(10 * s_count + n), 3)
+    plane = jax.random.normal(k_plane, (s_count, n, 37)).astype(dtype)
+    rows = jax.random.normal(k_rows, (n, 37), jnp.float32)
+    s = jax.random.randint(k_s, (n,), 0, s_count)
+    got = _write_rows(plane, s, rows)
+    want = plane.at[s, jnp.arange(n)].set(rows.astype(dtype))
+    bits = lambda a: np.asarray(a).view(f"u{a.dtype.itemsize}")  # noqa: E731
+    assert got.dtype == dtype
+    np.testing.assert_array_equal(bits(got), bits(want))
+    np.testing.assert_array_equal(bits(_select_rows(got, s)),
+                                  bits(rows.astype(dtype)))

@@ -11,12 +11,14 @@ every test worker collects the same tests and only the worker that runs
 this file loads the TPU compiler.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.fedspd import _write_rows
 from repro.kernels import gossip_mix as gm
 
 X = 97_271_808   # whisper-base parameter count (configs/whisper_base.py)
@@ -112,3 +114,18 @@ def test_int4_slab_layout_shapes_match_kernel():
     out = gm.int4_slab_layout(packed, scales, qblock=QB_SERVE)
     _, shapes = _cases()["mixture_dequant4"]
     assert [o.shape for o in out] == [s for s, _ in shapes[1:]]
+
+
+def test_plane_write_back_is_in_place_on_v5e(one_chip):
+    """The round step's write-back of the mixed rows into the donated
+    (S, N, X) plane compiles to one select over the plane in place: no
+    loop, no scatter, and no plane-sized copy."""
+    n = 2
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in [((S, n, X), jnp.float32), ((n,), jnp.int32),
+                                 ((n, X), jnp.float32)]]
+    text = jax.jit(_write_rows, donate_argnums=0).lower(
+        *args).compile().as_text()
+    assert "input_output_alias={ {}: (0, {}" in text
+    assert not re.search(r"\bwhile\(|\bscatter\(", text)
+    assert not re.search(rf"= f32\[{S},{n},{X}\]\S* copy\(", text)
