@@ -6,11 +6,19 @@ tiny (N ≤ a few hundred clients → ≤ 0.25 MB fp32) and is kept whole in VME
 for every grid step. The flattened parameter matrix C (N, X) with X up to
 tens of billions is tiled along X: grid = (n_x_blocks,), each step loads a
 (N, x_block) slab, does one (N×N)·(N×x_block) MXU matmul, and writes the
-mixed slab. x_block = 2048 keeps the slab (N=128 → 1 MB bf16 in + 1 MB out
-+ W) comfortably inside VMEM with room for double buffering, and the matmul
-K-dim = N is zero-padded to 8/128 alignment by Mosaic. Interpret mode
-(CPU validation) defaults to one whole-X block instead — there is no VMEM
-to respect and each grid step costs ~100 µs of interpreter overhead.
+mixed slab. A plane of at most 8 rows (one fp32 sublane tile) mixes on the
+VPU instead, as fp32 multiply-adds with W read as scalars from SMEM: at
+K = N ≤ 8 the HIGHEST-precision matmul, not HBM, sets the pace (on a v5e,
+(2, 97,271,808) fp32 takes at least 6.6 ms on the MXU at any slab width,
+2.4 ms on the VPU). The slab is sized in bytes (``_plan_blocks``): every
+slab-wide operand, the output included, with its rows padded to the dtype's
+sublane tile and two buffers each, shares a fixed VMEM budget. Each grid step has a
+fixed cost, so the slab is as wide as that budget allows: N=128 fp32 gets
+2048 columns, N=2 fp32 32,768 (a slab at 2048 columns moves only 32 KiB at
+N=2, and the step's overhead, not HBM, then sets the pace). The matmul
+K-dim = N is zero-padded to 8/128 alignment by Mosaic. Interpret mode (CPU
+validation) defaults to one whole-X block instead — there is no VMEM to
+respect and each grid step costs ~100 µs of interpreter overhead.
 
 Every entry point takes ``interpret=None``: Mosaic on a TPU backend, the
 interpreter elsewhere (``repro.kernels.resolve_interpret``). Mosaic tiles
@@ -55,25 +63,53 @@ def _mix_kernel(w_ref, c_ref, o_ref):
     o_ref[...] = _mxu(w, c).astype(o_ref.dtype)
 
 
-def _plan_blocks(x: int, x_block: int | None, interpret: bool) -> int:
+# VMEM for the slab-wide operands of one grid step, two buffers each
+# (Pallas double-buffers every blocked operand): 1 MiB a buffer for the
+# two-operand fp32 mix at N=128, which keeps its 2048 columns.
+_SLAB_VMEM_BYTES = 4 << 20
+
+
+def _col_bytes(*slabs) -> float:
+    """VMEM bytes per X column of one grid step. Each slab is ``(rows,
+    dtype)`` or ``(rows, dtype, columns per X column)``; rows are padded
+    to the dtype's sublane tile (8 for 32-bit, 32 for 8-bit), and every
+    slab is counted twice for its two buffers."""
+    total = 0.0
+    for rows, dtype, *per in slabs:
+        size = jnp.dtype(dtype).itemsize
+        tile = 32 // size
+        total += -(-rows // tile) * tile * size * (per[0] if per else 1)
+    return 2 * total
+
+
+def _budget_cols(col_bytes: float) -> int:
+    """The widest 128-lane-aligned slab whose buffers fit the budget."""
+    return max(128, int(_SLAB_VMEM_BYTES // col_bytes) // 128 * 128)
+
+
+def _plan_blocks(x: int, x_block: int | None, interpret: bool,
+                 col_bytes: float) -> int:
     """Block width for tiling the X axis.
 
-    The X grid exists to bound VMEM residency on real TPUs; interpret
-    mode (CPU validation / CI) has no VMEM and pays ~100 µs of
+    The X grid exists to bound VMEM residency on real TPUs, and every grid
+    step costs a fixed overhead, so the compiled default is the widest slab
+    whose buffers fit ``_SLAB_VMEM_BYTES`` at ``col_bytes`` (``_col_bytes``
+    of the kernel's slab-wide operands): a narrow plane gets a wide slab.
+    Interpret mode (CPU validation / CI) has no VMEM and pays ~100 µs of
     interpreter overhead PER GRID STEP, so its default is one whole-X
-    block. An explicit ``x_block`` is always honored (the multi-block
-    path is exercised in tests via small explicit blocks).
+    block. An explicit ``x_block`` is always honored (the multi-block path
+    is exercised in tests via small explicit blocks).
 
     A requested ``x_block`` is re-planned into ``ceil(X / x_block)``
     equal-width blocks instead of always tiling at the full width: the
     trailing block's waste drops from up to ``x_block - 1`` columns to
     under one lane tile. Blocks stay 128-lane aligned whenever the
-    caller's ``x_block`` is (the Mosaic tiling constraint); tiny or
-    unaligned test sizes fall back to align=1. Non-dividing trailing
-    blocks are handled by Pallas's edge masking — no host-side zero-pad
-    / crop copies of the plane."""
+    caller's ``x_block`` is (the Mosaic tiling constraint), and never grow
+    past it; tiny or unaligned test sizes fall back to align=1.
+    Non-dividing trailing blocks are handled by Pallas's edge masking — no
+    host-side zero-pad / crop copies of the plane."""
     if x_block is None:
-        x_block = x if interpret else 2048
+        x_block = x if interpret else _budget_cols(col_bytes)
     x_block = min(x_block, x)
     align = 128 if (x_block % 128 == 0 and x >= 128) else 1
     k = -(-x // x_block)          # number of grid steps
@@ -82,28 +118,65 @@ def _plan_blocks(x: int, x_block: int | None, interpret: bool) -> int:
 
 
 def _plan_quant_blocks(xp: int, qblock: int, x_block: int | None,
-                       interpret: bool) -> int:
+                       interpret: bool, col_bytes: float) -> int:
     """Slab width for the kernels that read per-block scales: a multiple
     of ``qblock`` so each scale belongs to one slab, and — compiled — of
     ``128 * qblock`` so the (rows, x_block // qblock) scale block fills
-    whole 128-lane tiles. A slab at least as wide as the plane becomes
-    one whole-array block, which Mosaic accepts at any width."""
-    x_block = _plan_blocks(xp, x_block, interpret)
+    whole 128-lane tiles. The compiled default is the budget's width in
+    whole such units (one unit at least), so the rounding up keeps it in
+    the budget. A slab at least as wide as the plane becomes one
+    whole-array block, which Mosaic accepts at any width."""
     unit = qblock if interpret else 128 * qblock
+    if x_block is None and not interpret:
+        x_block = max(unit, _budget_cols(col_bytes) // unit * unit)
+    x_block = _plan_blocks(xp, x_block, interpret, col_bytes)
     return min(-(-x_block // unit) * unit, xp)
+
+
+# Planes of at most one fp32 sublane tile of rows mix on the VPU.
+_VPU_ROWS = 8
+
+
+def _mix_rows_kernel(w_ref, c_ref, o_ref):
+    """W·C for a plane of few rows: o_i = Σ_j W[i, j]·c_j as fp32
+    multiply-adds on the VPU, the weights read as scalars from SMEM. On
+    the MXU a K = N ≤ 8 contraction fills a sliver of the array, six bf16
+    passes deep at HIGHEST; fp32 products are at least as precise."""
+    n = c_ref.shape[0]
+    rows = [c_ref[j:j + 1, :].astype(jnp.float32) for j in range(n)]
+    for i in range(n):
+        acc = w_ref[i, 0] * rows[0]
+        for j in range(1, n):
+            acc = acc + w_ref[i, j] * rows[j]
+        o_ref[i:i + 1, :] = acc.astype(o_ref.dtype)
 
 
 def gossip_mix_flat(
     w: jnp.ndarray,  # (N, N) row-stochastic mixing weights
     c: jnp.ndarray,  # (N, X) flattened per-client parameters
     *,
-    x_block: int | None = None,  # default: 2048 compiled, whole-X interpret
+    x_block: int | None = None,  # default: VMEM budget compiled, whole-X interpret
     interpret: bool | None = None,
 ) -> jnp.ndarray:
+    """C ← W·C over the (N, X) plane in one ``pallas_call``: an MXU
+    matmul per slab, or for N ≤ ``_VPU_ROWS`` the VPU contraction
+    (``_mix_rows_kernel``, W as a scalar-prefetch operand in SMEM)."""
     n, x = c.shape
     interpret = resolve_interpret(interpret)
-    x_block = _plan_blocks(x, x_block, interpret)
+    x_block = _plan_blocks(x, x_block, interpret,
+                           _col_bytes((n, c.dtype), (n, c.dtype)))
     grid = (-(-x // x_block),)
+    out_shape = jax.ShapeDtypeStruct((n, x), c.dtype)
+    if n <= _VPU_ROWS:
+        slab = pl.BlockSpec((n, x_block), lambda i, w: (0, i))
+        return pl.pallas_call(
+            _mix_rows_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=grid, in_specs=[slab],
+                out_specs=slab),
+            out_shape=out_shape,
+            interpret=interpret,
+        )(w.astype(jnp.float32), c)
     return pl.pallas_call(
         _mix_kernel,
         grid=grid,
@@ -112,7 +185,7 @@ def gossip_mix_flat(
             pl.BlockSpec((n, x_block), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((n, x_block), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((n, x), c.dtype),
+        out_shape=out_shape,
         interpret=interpret,
     )(w, c)
 
@@ -127,7 +200,7 @@ def gossip_mix_stack(
     w: jnp.ndarray,  # (N, N) mixing weights, shared by every stack
     c: jnp.ndarray,  # (S, N, X) packed center stacks
     *,
-    x_block: int | None = None,  # default: 2048 compiled, whole-X interpret
+    x_block: int | None = None,  # default: VMEM budget compiled, whole-X interpret
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Mix EVERY cluster stack of a packed (S, N, X) plane with the same
@@ -137,7 +210,8 @@ def gossip_mix_stack(
     pytree layout pays S × n_leaves kernel launches for the same traffic."""
     s, n, x = c.shape
     interpret = resolve_interpret(interpret)
-    x_block = _plan_blocks(x, x_block, interpret)
+    x_block = _plan_blocks(x, x_block, interpret,
+                           _col_bytes((n, c.dtype), (n, c.dtype)))
     return pl.pallas_call(
         _mix_stack_kernel,
         grid=(s, -(-x // x_block)),
@@ -200,7 +274,7 @@ def gossip_mix_sparse(
     c: jnp.ndarray,           # (N, X) plane slab, ZERO on dead columns
     col_active: jnp.ndarray,  # (X,) float {0,1}: any client keeps column
     *,
-    x_block: int | None = None,  # default: 2048 compiled, whole-X interpret
+    x_block: int | None = None,  # default: VMEM budget compiled, whole-X interpret
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Mask-aware W·C for the sparse (DisPFL) exchange: the grid still
@@ -219,7 +293,8 @@ def gossip_mix_sparse(
             f"width {x}"
         )
     interpret = resolve_interpret(interpret)
-    x_block = _plan_blocks(x, x_block, interpret)
+    x_block = _plan_blocks(x, x_block, interpret,
+                           _col_bytes((n, c.dtype), (n, c.dtype)))
     k = -(-x // x_block)
     return pl.pallas_call(
         _mix_sparse_kernel,
@@ -256,7 +331,7 @@ def gossip_mix_dequant(
     scales: jnp.ndarray,  # (N, Xp // qblock) fp32 per-block scales
     *,
     qblock: int,                 # quantization block width along X
-    x_block: int | None = None,  # default: 2048 compiled, whole-X interpret
+    x_block: int | None = None,  # default: VMEM budget compiled, whole-X interpret
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Compressed exchange in ONE ``pallas_call``: dequantize the int8
@@ -285,7 +360,10 @@ def gossip_mix_dequant(
             f"tile with qblock={qblock}"
         )
     interpret = resolve_interpret(interpret)
-    x_block = _plan_quant_blocks(xp, qblock, x_block, interpret)
+    x_block = _plan_quant_blocks(
+        xp, qblock, x_block, interpret,
+        _col_bytes((n, q.dtype), (n, scales.dtype, 1 / qblock),
+                   (m, jnp.float32)))
     return pl.pallas_call(
         functools.partial(_mix_dequant_kernel, qblock=qblock),
         grid=(-(-xp // x_block),),
@@ -328,7 +406,7 @@ def gossip_mix_dequant_masked(
     mask: jnp.ndarray,    # (N, X) float {0,1} per-sender masks, X <= Xp
     *,
     qblock: int,                 # quantization block width along X
-    x_block: int | None = None,  # default: 2048 compiled, whole-X interpret
+    x_block: int | None = None,  # default: VMEM budget compiled, whole-X interpret
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Masked variant of ``gossip_mix_dequant`` for the sparse exchange's
@@ -353,7 +431,10 @@ def gossip_mix_dequant_masked(
     mask = jnp.pad(mask.astype(jnp.float32),
                    ((0, 0), (0, xp - mask.shape[1])))
     interpret = resolve_interpret(interpret)
-    x_block = _plan_quant_blocks(xp, qblock, x_block, interpret)
+    x_block = _plan_quant_blocks(
+        xp, qblock, x_block, interpret,
+        _col_bytes((n, q.dtype), (n, scales.dtype, 1 / qblock),
+                   (n, mask.dtype), (m_rows, jnp.float32)))
     k = -(-xp // x_block)
     slab = pl.BlockSpec((n, x_block), lambda i, a: (0, i))
     return pl.pallas_call(
@@ -523,7 +604,7 @@ def gossip_mix_fused_dp(
     noise,               # (N, X) standard Gaussian draw; None iff sigma == 0
     sigma: float,        # dp_clip * dp_noise_multiplier (static)
     *,
-    x_block: int | None = None,  # default: 2048 compiled, whole-X interpret
+    x_block: int | None = None,  # default: VMEM budget compiled, whole-X interpret
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """DP round in a single streaming pass: clip·scale + noise + W·C fused
@@ -537,20 +618,22 @@ def gossip_mix_fused_dp(
     assert c_new.shape == (n, x)
     assert (noise is None) == (sigma <= 0.0)
     scale = scale.reshape(n, 1)
+    planes = [c_old, c_new]
+    if sigma > 0.0:
+        assert noise.shape == (n, x)
+        planes.append(noise)
     interpret = resolve_interpret(interpret)
-    x_block = _plan_blocks(x, x_block, interpret)
+    x_block = _plan_blocks(
+        x, x_block, interpret,
+        _col_bytes((n, c_old.dtype), *[(n, p.dtype) for p in planes]))
     slab = pl.BlockSpec((n, x_block), lambda i: (0, i))
     in_specs = [
         pl.BlockSpec((n, n), lambda i: (0, 0)),
         slab,
         slab,
         pl.BlockSpec((n, 1), lambda i: (0, 0)),
-    ]
-    operands = [w, c_old, c_new, scale]
-    if sigma > 0.0:
-        assert noise.shape == (n, x)
-        in_specs.append(slab)
-        operands.append(noise)
+    ] + [slab] * (len(planes) - 2)
+    operands = [w, c_old, c_new, scale] + planes[2:]
     return pl.pallas_call(
         functools.partial(_mix_dp_kernel, sigma=sigma),
         grid=(-(-x // x_block),),

@@ -211,3 +211,135 @@ def test_make_mix_fn_rejects_unknown_backend():
     spec = GossipSpec.from_graph(make_graph("er", 6, 3.0, seed=0))
     with pytest.raises(ValueError, match="unknown gossip backend"):
         make_mix_fn(spec, backend="cuda")
+
+
+# --- slab planning of the X-tiled mixes (compiled plans, traced on CPU) ----
+
+X_WHISPER = 97_271_808   # whisper-base parameter count, the train cell's X
+
+
+def _compiled_plan(fn, shapes):
+    """Grid and ``(block sizes, array aval)`` of each blocked operand of
+    the one ``pallas_call`` that ``fn`` traces to with ``interpret=False``
+    (traced only: nothing is lowered or run)."""
+    args = [jax.ShapeDtypeStruct(s, d) for s, d in shapes]
+    eqns = [e for e in jax.make_jaxpr(fn)(*args).jaxpr.eqns
+            if e.primitive.name == "pallas_call"]
+    assert len(eqns) == 1
+    gmap = eqns[0].params["grid_mapping"]
+    blocks = [(tuple(getattr(b, "block_size", b) for b in bm.block_shape),
+               bm.array_aval) for bm in gmap.block_mappings]
+    return gmap.grid, blocks
+
+
+def _slab_vmem_bytes(blocks):
+    """The planner's VMEM estimate from the kernel's own blocks: each
+    operand tiled along X (block narrower than its array), rows padded to
+    the sublane tile, lanes to 128, item size, two buffers."""
+    total = 0
+    for shape, aval in blocks:
+        if shape[-1] == aval.shape[-1]:
+            continue                       # W, clip scales: not slab-wide
+        size = aval.dtype.itemsize
+        tile = 32 // size
+        rows = -(-shape[-2] // tile) * tile
+        lanes = -(-shape[-1] // 128) * 128
+        total += int(np.prod(shape[:-2])) * rows * lanes * size * 2
+    return total
+
+
+def _tiled_mix_cases(n):
+    """name -> (fn, shapes, narrowest slab Mosaic allows) for every X-tiled
+    kernel of kernels/gossip_mix at n clients and the train cell's X."""
+    from repro.kernels import gossip_mix as gm
+
+    f32, i8, qb, x = jnp.float32, jnp.int8, 256, X_WHISPER
+    xp = -(-x // qb) * qb
+    w, plane = ((n, n), f32), ((n, x), f32)
+    quant = [w, ((n, xp), i8), ((n, xp // qb), f32)]
+    return {
+        "flat": (lambda w_, c: gm.gossip_mix_flat(w_, c, interpret=False),
+                 [w, plane], 128),
+        "stack": (lambda w_, c: gm.gossip_mix_stack(w_, c, interpret=False),
+                  [w, ((2, n, x), f32)], 128),
+        "sparse": (lambda w_, c, a: gm.gossip_mix_sparse(
+            w_, c, a, interpret=False), [w, plane, ((x,), f32)], 128),
+        "fused_dp": (lambda w_, co, cn, sc, nz: gm.gossip_mix_fused_dp(
+            w_, co, cn, sc, nz, 0.5, interpret=False),
+            [w, plane, plane, ((n, 1), f32), plane], 128),
+        "fused_dp_clip": (lambda w_, co, cn, sc: gm.gossip_mix_fused_dp(
+            w_, co, cn, sc, None, 0.0, interpret=False),
+            [w, plane, plane, ((n, 1), f32)], 128),
+        "dequant": (lambda w_, q, sc: gm.gossip_mix_dequant(
+            w_, q, sc, qblock=qb, interpret=False), quant, 128 * qb),
+        "dequant_masked": (lambda w_, q, sc, m: gm.gossip_mix_dequant_masked(
+            w_, q, sc, m, qblock=qb, interpret=False), quant + [plane],
+            128 * qb),
+        "serve_dequant": (lambda u, q, sc: gm.gossip_mix_dequant(
+            u, q, sc, qblock=64, interpret=False),
+            [((32, n), f32), ((n, -(-x // 64) * 64), i8),
+             ((n, -(-x // 64)), f32)], 128 * 64),
+    }
+
+
+@pytest.mark.parametrize("name,n", [
+    (name, n) for name in sorted(_tiled_mix_cases(2))
+    # an N=128 quantized slab is past the budget at its narrowest
+    for n in ((2, 4) if "dequant" in name else (2, 4, 128))])
+def test_planned_slab_fits_vmem_budget(name, n):
+    """Every X-tiled mix plans a slab whose double-buffered operands fit
+    the planner's VMEM budget; a quantized kernel may exceed it only at
+    the narrowest slab Mosaic allows (128 scale blocks)."""
+    from repro.kernels.gossip_mix import _SLAB_VMEM_BYTES
+
+    fn, shapes, narrowest = _tiled_mix_cases(n)[name]
+    _, blocks = _compiled_plan(fn, shapes)
+    width = max(s[-1] for s, a in blocks if s[-1] < a.shape[-1]
+                and a.shape[-1] >= X_WHISPER)
+    assert width % 128 == 0
+    assert (_slab_vmem_bytes(blocks) <= _SLAB_VMEM_BYTES
+            or width == narrowest)
+
+
+@pytest.mark.parametrize("n,x_block,width,max_steps", [
+    (2, None, 32_768, 3_000),      # the train cell: wide slab, few steps
+    (128, None, 2_048, None),      # large N keeps its 2048 columns
+    (2, 2_048, 2_048, None),       # an explicit x_block is honoured
+    (2, 4_096, 4_096, None),
+])
+def test_flat_mix_plan(n, x_block, width, max_steps):
+    """gossip_mix_flat's compiled plan at the train cell's X: the default
+    slab is sized by bytes, and an explicit x_block wins."""
+    from repro.kernels.gossip_mix import gossip_mix_flat
+
+    (steps,), blocks = _compiled_plan(
+        lambda w, c: gossip_mix_flat(w, c, x_block=x_block,
+                                     interpret=False),
+        [((n, n), jnp.float32), ((n, X_WHISPER), jnp.float32)])
+    w_block = [] if n <= 8 else [(n, n)]     # N <= 8: W rides in SMEM
+    assert [s for s, _ in blocks] == w_block + [(n, width), (n, width)]
+    assert steps == -(-X_WHISPER // width)
+    if max_steps:
+        assert steps <= max_steps
+
+
+@pytest.mark.parametrize("n", [2, 8, 16])   # VPU, VPU, MXU contraction
+def test_flat_mix_matches_mix_dense(n):
+    """gossip_mix_flat equals core/gossip.mix_dense on either contraction
+    path (VPU for N ≤ 8, MXU above): a weighted adjacency gives a
+    row-stochastic W of unequal weights, and 1000 columns at x_block 384
+    end in a ragged trailing block."""
+    from repro.core.gossip import GossipSpec, fedspd_weight_matrix, mix_dense
+    from repro.graphs.topology import make_graph
+    from repro.kernels.gossip_mix import gossip_mix_flat
+
+    spec = GossipSpec.from_graph(make_graph("complete", n, n - 1, seed=n))
+    ks = jax.random.split(jax.random.PRNGKey(n), 3)
+    adj = jax.random.uniform(ks[0], (n, n), jnp.float32, 0.1, 1.0)
+    s = jax.random.randint(ks[1], (n,), 0, 2)
+    c = jax.random.normal(ks[2], (n, 1000), jnp.float32)
+    w = fedspd_weight_matrix(spec, s, adj=adj)
+    assert len(np.unique(np.round(np.asarray(w), 6))) > 3
+    out = gossip_mix_flat(w, c, x_block=384, interpret=True)
+    want = mix_dense(spec, {"c": c}, s, adj=adj)["c"]
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-6)

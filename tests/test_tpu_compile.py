@@ -54,8 +54,28 @@ def _xp(qblock):
     return -(-X // qblock) * qblock
 
 
+def _fp32_cases(n):
+    """The fp32 X-tiled mixes at n clients: name -> (fn, shapes)."""
+    f32 = jnp.float32
+    w = ((n, n), f32)
+    plane = ((n, X), f32)
+    return {
+        "flat": (lambda w_, c: gm.gossip_mix_flat(w_, c, interpret=False),
+                 [w, plane]),
+        "stack": (lambda w_, c: gm.gossip_mix_stack(w_, c, interpret=False),
+                  [w, ((S, n, X), f32)]),
+        "sparse": (lambda w_, c, a: gm.gossip_mix_sparse(
+            w_, c, a, interpret=False), [w, plane, ((X,), f32)]),
+        "fused_dp": (lambda w_, co, cn, sc, nz: gm.gossip_mix_fused_dp(
+            w_, co, cn, sc, nz, 0.5, interpret=False),
+            [w, plane, plane, ((n, 1), f32), plane]),
+    }
+
+
 def _cases():
-    """name -> (kernel fn taking shape args, [(shape, dtype), ...])."""
+    """name -> (kernel fn taking shape args, [(shape, dtype), ...]): the
+    fp32 mixes at N clients and, as ``<name>_n2``, at the train cell's 2
+    (the widest slab the planner gives), and the quantized kernels."""
     f32, i8 = jnp.float32, jnp.int8
     w = ((N, N), f32)
     plane = ((N, X), f32)
@@ -71,21 +91,14 @@ def _cases():
              ((S, xk // qb), f32)])
 
     return {
-        "flat": (lambda w_, c: gm.gossip_mix_flat(w_, c, interpret=False),
-                 [w, plane]),
-        "stack": (lambda w_, c: gm.gossip_mix_stack(w_, c, interpret=False),
-                  [w, ((S, N, X), f32)]),
-        "sparse": (lambda w_, c, a: gm.gossip_mix_sparse(
-            w_, c, a, interpret=False), [w, plane, ((X,), f32)]),
+        **_fp32_cases(N),
+        **{f"{k}_n2": v for k, v in _fp32_cases(2).items()},
         "dequant": (lambda w_, q, sc: gm.gossip_mix_dequant(
             w_, q, sc, qblock=QB, interpret=False),
             [w, ((N, xp), i8), ((N, xp // QB), f32)]),
         "dequant_masked": (lambda w_, q, sc, m: gm.gossip_mix_dequant_masked(
             w_, q, sc, m, qblock=QB, interpret=False),
             [w, ((N, xp), i8), ((N, xp // QB), f32), plane]),
-        "fused_dp": (lambda w_, co, cn, sc, nz: gm.gossip_mix_fused_dp(
-            w_, co, cn, sc, nz, 0.5, interpret=False),
-            [w, plane, plane, ((N, 1), f32), plane]),
         "mixture_dequant4": int4_case(QB_SERVE),
         "mixture_dequant4_qb256": int4_case(QB),
         "serve_dequant_int8": (lambda u, q, sc: gm.gossip_mix_dequant(
@@ -103,6 +116,16 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_train_cell_mix_is_one_kernel_without_copies_on_v5e(one_chip):
+    """At the train cell's N=2 the fp32 exchange compiles to one Mosaic
+    call on its wide slab, with no copy of the (2, X) plane around it."""
+    fn, shapes = _cases()["flat_n2"]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert not re.search(rf"= f32\[2,{X}\]\S* copy\(", text)
 
 
 def test_int4_slab_layout_shapes_match_kernel():
